@@ -21,11 +21,7 @@ import json
 import numpy as np
 
 from ..core import integrity
-
-try:  # device-side checksum when jax arrays flow through
-    from ..kernels import ops as kops
-except Exception:  # pragma: no cover
-    kops = None
+from ..kernels import ops as kops
 
 
 def flatten_tree(tree, prefix=""):
@@ -72,7 +68,7 @@ def bytes_to_leaf(raw: np.ndarray, meta: dict):
 
 
 def checksum_leaf(raw: np.ndarray, on_device: bool = False) -> int:
-    if on_device and kops is not None:
+    if on_device:
         return kops.checksum_array(raw)
     return integrity.checksum(raw)
 

@@ -19,6 +19,10 @@ Layout notes (MXU/VREG):
     accumulators, flash-2 style.
   * causal / sliding-window / prefix-LM masks are built from iota + the
     grid position — no mask tensors in HBM.
+  * the per-row statistics (lse, delta) travel as (B, n_kv, G, 1, S): a
+    block's trailing two dims must be (8k, 128k) or the array's own, and
+    (1, bq) over (1, S) is legal for any group width G, where (1, bq) over
+    (G, S) is not.
 
 Oracle: ``repro.models.attention_flash.blockwise_attention`` (pure jnp);
 tests sweep shapes/masks in interpret mode, including gradients.
@@ -86,7 +90,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc,
     def _emit():
         l = jnp.maximum(l_sc[...], 1e-30)
         o_ref[0, 0, 0] = (acc[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, 0] = (m_sc[...] + jnp.log(l)).astype(jnp.float32)
+        lse_ref[0, 0, 0, 0] = (m_sc[...] + jnp.log(l)).astype(jnp.float32)
 
 
 def flash_fwd_pallas(q, k, v, *, causal=True, window=0, prefix=0,
@@ -118,12 +122,12 @@ def flash_fwd_pallas(q, k, v, *, causal=True, window=0, prefix=0,
         out_specs=[
             pl.BlockSpec((1, 1, 1, bq, D),
                          lambda b, h, g, i, j: (b, h, g, i, 0)),
-            pl.BlockSpec((1, 1, 1, bq),
-                         lambda b, h, g, i, j: (b, h, g, i)),
+            pl.BlockSpec((1, 1, 1, 1, bq),
+                         lambda b, h, g, i, j: (b, h, g, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, G, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, G, S), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, G, 1, S), jnp.float32),
         ],
         scratch_shapes=[
             # VMEM accumulators persist across the kv sweep
@@ -133,7 +137,7 @@ def flash_fwd_pallas(q, k, v, *, causal=True, window=0, prefix=0,
         ],
         interpret=interpret,
     )(q, k, v)
-    return out, lse
+    return out, lse.reshape(B, H, G, S)
 
 
 # ======================================================================
@@ -153,8 +157,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0, 0]
-    dlt = dlt_ref[0, 0, 0]
+    lse = lse_ref[0, 0, 0, 0]
+    dlt = dlt_ref[0, 0, 0, 0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -187,8 +191,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0, 0]
-    dlt = dlt_ref[0, 0, 0]
+    lse = lse_ref[0, 0, 0, 0]
+    dlt = dlt_ref[0, 0, 0, 0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -218,6 +222,8 @@ def flash_bwd_pallas(q, k, v, do, lse, delta, *, causal=True, window=0,
     assert S % bq == 0 and Sk % bk == 0
     nq, nk = S // bq, Sk // bk
     scale = scale if scale else 1.0 / np.sqrt(D)
+    lse = lse.reshape(B, H, G, 1, S)
+    delta = delta.reshape(B, H, G, 1, S)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, window=window,
@@ -230,8 +236,10 @@ def flash_bwd_pallas(q, k, v, do, lse, delta, *, causal=True, window=0,
             pl.BlockSpec((1, 1, bk, D), lambda b, h, g, i, j: (b, h, j, 0)),
             pl.BlockSpec((1, 1, 1, bq, D),
                          lambda b, h, g, i, j: (b, h, g, i, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, g, i, j: (b, h, g, i)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, g, i, j: (b, h, g, i)),
+            pl.BlockSpec((1, 1, 1, 1, bq),
+                         lambda b, h, g, i, j: (b, h, g, 0, i)),
+            pl.BlockSpec((1, 1, 1, 1, bq),
+                         lambda b, h, g, i, j: (b, h, g, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, bq, D),
                                lambda b, h, g, i, j: (b, h, g, i, 0)),
@@ -252,8 +260,10 @@ def flash_bwd_pallas(q, k, v, do, lse, delta, *, causal=True, window=0,
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j, g, i: (b, h, j, 0)),
             pl.BlockSpec((1, 1, 1, bq, D),
                          lambda b, h, j, g, i: (b, h, g, i, 0)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, j, g, i: (b, h, g, i)),
-            pl.BlockSpec((1, 1, 1, bq), lambda b, h, j, g, i: (b, h, g, i)),
+            pl.BlockSpec((1, 1, 1, 1, bq),
+                         lambda b, h, j, g, i: (b, h, g, 0, i)),
+            pl.BlockSpec((1, 1, 1, 1, bq),
+                         lambda b, h, j, g, i: (b, h, g, 0, i)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j, g, i: (b, h, j, 0)),

@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 These own all the padding/reshaping so the kernels only ever see aligned
-tiles, and they pick interpret mode automatically (interpret=True on CPU,
-compiled on TPU).  The host-side entry points (``checksum_array``) reproduce
+tiles, and they pick the mode from the backend: compiled on TPU, interpret
+mode on CPU (the tests), and an error on any other backend rather than a
+silent interpreter run.  The host-side entry points (``checksum_array``) reproduce
 ``repro.core.integrity.checksum`` exactly, including the length mix.
 """
 from __future__ import annotations
@@ -21,8 +22,14 @@ from .shard_pack import CELL_COLS, shard_pack_pallas, shard_unpack_pallas
 _MASK64 = (1 << 64) - 1
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels compile for TPU and interpret on "
+                       f"CPU; the {backend!r} backend has neither")
 
 
 def _splitmix64(x: int) -> int:
@@ -37,45 +44,32 @@ def _weights_tile() -> np.ndarray:
     return np.asarray(ref.weight_powers(TILE)).reshape(TILE_ROWS, TILE_COLS)
 
 
-@functools.lru_cache(maxsize=256)
-def _tile_scales(n_tiles: int) -> np.ndarray:
-    w_tile = pow(int(ref.WEIGHT), TILE, 1 << 32)
-    out = np.empty(n_tiles, np.uint32)
-    acc = 1
-    for i in range(n_tiles):
-        out[i] = acc
-        acc = (acc * w_tile) & 0xFFFFFFFF
-    return out
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _checksum_words_device(words: jnp.ndarray, scales: jnp.ndarray,
-                           weights: jnp.ndarray,
+def _checksum_words_device(words: jnp.ndarray, weights: jnp.ndarray,
                            interpret: bool = True) -> jnp.ndarray:
     n = words.shape[0]
     pad = (-n) % TILE
     if pad:
         words = jnp.concatenate([words, jnp.zeros(pad, jnp.uint32)])
     n_tiles = words.shape[0] // TILE
-    out = checksum_words_pallas(
-        words.reshape(n_tiles * TILE_ROWS, TILE_COLS),
-        scales, weights, interpret=interpret)
-    return out[0, 0]
+    lanes = checksum_words_pallas(
+        words.reshape(n_tiles * TILE_ROWS, TILE_COLS), weights,
+        interpret=interpret)
+    return jnp.sum(lanes, dtype=jnp.uint32)
 
 
 def checksum_array(x, interpret: bool | None = None) -> int:
     """Device-side checksum of any array; bit-identical to
     ``repro.core.integrity.checksum`` of the array's bytes."""
-    interpret = _interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     arr = np.ascontiguousarray(np.asarray(x))
     nbytes = arr.nbytes
     if nbytes == 0:
         return 0 ^ (_splitmix64(0) & 0xFFFFFFFF)
     u8 = jnp.asarray(arr.view(np.uint8).reshape(-1))
     words = ref.bytes_to_words(u8)
-    n_tiles = -(-int(words.shape[0]) // TILE)
-    acc = int(_checksum_words_device(words, _tile_scales(n_tiles),
-                                     _weights_tile(), interpret=interpret))
+    acc = int(_checksum_words_device(words, _weights_tile(),
+                                     interpret=interpret))
     return acc ^ (_splitmix64(nbytes) & 0xFFFFFFFF)
 
 
@@ -93,7 +87,7 @@ def _quant_groups(flat: jnp.ndarray, interpret: bool = True):
 def quantize(x: jnp.ndarray, interpret: bool | None = None):
     """-> (q int8 [n_groups, GROUP], scales [n_groups, 1], meta) where meta
     carries the original shape/dtype/length for dequantize()."""
-    interpret = _interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     meta = (x.shape, x.dtype, int(np.prod(x.shape)) if x.shape else 1)
     q, s = _quant_groups(jnp.asarray(x, jnp.float32).reshape(-1),
                          interpret=interpret)
@@ -107,7 +101,7 @@ def _dequant_groups(q: jnp.ndarray, s: jnp.ndarray, interpret: bool = True):
 
 def dequantize(q: jnp.ndarray, scales: jnp.ndarray, meta,
                interpret: bool | None = None) -> jnp.ndarray:
-    interpret = _interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     shape, dtype, n = meta
     flat = _dequant_groups(q, scales, interpret=interpret).reshape(-1)
     return flat[:n].reshape(shape).astype(dtype)
@@ -122,7 +116,7 @@ def shard_pack(x: jnp.ndarray, width: int, cell_bytes: int = 1 << 16,
     -> (packed (width, cells_per_target, cell_rows, 128) uint32, meta).
     cell_bytes must be a multiple of 512 (=128 lanes x 4 B).
     """
-    interpret = _interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     assert cell_bytes % (CELL_COLS * 4) == 0
     cell_words = cell_bytes // 4
     cell_rows = cell_words // CELL_COLS
@@ -142,7 +136,7 @@ def shard_pack(x: jnp.ndarray, width: int, cell_bytes: int = 1 << 16,
 def shard_unpack(packed: jnp.ndarray, meta,
                  interpret: bool | None = None) -> np.ndarray:
     """Inverse: -> original raw bytes as np.uint8[orig_nbytes]."""
-    interpret = _interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     orig_nbytes, cell_bytes, width = meta
     cells = shard_unpack_pallas(packed, interpret=interpret)
     words = np.asarray(cells).reshape(-1).astype(np.uint32)
@@ -192,7 +186,7 @@ def _pallas_flash_fwd(q, k, v, n_kv, causal, window, prefix, bq, bk):
     out5, lse = flash_fwd_pallas(q5, k4, v4, causal=causal, window=window,
                                  prefix=prefix, bq=bq, bk=bk,
                                  scale=1.0 / float(np.sqrt(D0)),
-                                 interpret=_interpret())
+                                 interpret=interpret_mode())
     out = out5[..., :D0].transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, D0)
     return out, (q, k, v, out, lse)
 
@@ -214,7 +208,7 @@ def _pallas_flash_bwd(n_kv, causal, window, prefix, bq, bk, res, dout):
                                      causal=causal, window=window,
                                      prefix=prefix, bq=bq, bk=bk,
                                      scale=1.0 / float(np.sqrt(D)),
-                                     interpret=_interpret())
+                                     interpret=interpret_mode())
     dq = dq5[..., :D0].transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, D0) \
         .astype(q.dtype)
     dk = dk4[..., :D0].transpose(0, 2, 1, 3).astype(k.dtype)

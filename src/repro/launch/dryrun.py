@@ -177,8 +177,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # older jax returns [dict]
-            cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     st = hlo_analyze(hlo, bucket_re="flashattn" if pallas_attn else None)
 

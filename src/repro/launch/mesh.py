@@ -21,20 +21,29 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+
+def _auto_mesh(shape: tuple, axes: tuple) -> Mesh:
+    """``jax.make_mesh`` with Auto axes.  Its default is Explicit axes,
+    under which the sharding rules' specs become part of every array's
+    type and the embedding gather's output spec names one axis twice
+    (``DuplicateSpecError``); Auto axes leave propagation to GSPMD, which
+    is what these rules were written for."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> Mesh:
     """Whatever devices exist, as a 1x1 (data, model) mesh per device count
     — used by smoke tests and the CPU examples."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
 
 
 def mesh_axes(mesh: Mesh) -> tuple:

@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..kernels.ops import interpret_mode
 from ..kernels.quantize import (BLOCK_GROUPS, GROUP, dequantize_pallas,
                                 quantize_pallas)
 from ..models import forward_train
@@ -38,7 +39,7 @@ def _compress_leaf(g: jnp.ndarray, interpret: bool) -> jnp.ndarray:
 
 def compress_grads(grads, interpret: bool | None = None):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     return jax.tree.map(lambda g: _compress_leaf(g, interpret), grads)
 
 

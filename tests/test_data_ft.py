@@ -95,6 +95,4 @@ def test_hlo_cost_scan_multiplier():
     assert r["hbm_bytes"] > 0
     # unscaled XLA report counts the body once: must be 8x smaller
     cost = c.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     assert float(cost["flops"]) * 8 == r["flops"]

@@ -18,7 +18,7 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 ])
 def test_dryrun_cell_compiles(args, expect_dom, tmp_path):
     env = {"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin",
-           "HOME": "/tmp"}
+           "HOME": "/tmp", "JAX_PLATFORMS": "cpu"}
     import os
     env.update({k: v for k, v in os.environ.items()
                 if k not in env and not k.startswith("XLA")})
@@ -36,6 +36,16 @@ def test_dryrun_cell_compiles(args, expect_dom, tmp_path):
     assert r["compute_s"] >= 0 and r["memory_s"] > 0
     assert res["per_device"]["hlo_flops"] > 0
     assert res["n_devices"] == (512 if "--multi-pod" in args else 256)
+
+
+def test_host_mesh_has_auto_axes():
+    """Meshes are built with Auto axes: under jax.make_mesh's default
+    (Explicit) the embedding gather's output spec repeats an axis."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
 
 
 def test_sharding_rules_divisibility():

@@ -36,11 +36,21 @@ def test_checksum_kernel_matches_jnp_ref():
     words = jnp.asarray(rng.integers(0, 2**32, 4 * TILE, dtype=np.uint32))
     expect = int(ref.checksum_words(words))
     n_tiles = 4
-    scales = jnp.asarray(ops._tile_scales(n_tiles))
     weights = jnp.asarray(ops._weights_tile())
-    got = checksum_words_pallas(words.reshape(n_tiles * 8, 128), scales,
-                                weights)[0, 0]
+    lanes = checksum_words_pallas(words.reshape(n_tiles * 8, 128), weights)
+    got = jnp.sum(lanes, dtype=jnp.uint32)
     assert int(got) == expect
+
+
+def test_interpret_mode_follows_backend(monkeypatch):
+    """Compiled on TPU, interpreted on CPU, refused anywhere else."""
+    import jax
+    for backend, want in (("tpu", False), ("cpu", True)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert ops.interpret_mode() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.interpret_mode()
 
 
 def test_checksum_order_sensitive():

@@ -31,6 +31,51 @@ def test_train_survives_injected_failure():
     assert out["final_loss"] < out["first_loss"]
 
 
+def test_train_device_error_is_not_recovered(monkeypatch):
+    """Recovery is for the store's failures: a JAX runtime error (a device
+    OOM, say) inside the loop ends the run instead of restoring."""
+    import jax
+    from repro.data import Prefetcher
+    from repro.launch.train import run
+
+    def failing_batches(self, batch, seq, seed=0):
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: injected")
+        yield
+
+    monkeypatch.setattr(Prefetcher, "batches", failing_batches)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="injected"):
+        run(_train_args(steps=4))
+
+
+def test_train_vocab_defaults_to_config():
+    """Without --vocab the run keeps the config's vocabulary: the
+    published one under --no-smoke, the smoke variant's 256 otherwise."""
+    from repro.launch.train import model_config, parse_args
+    full = model_config(parse_args(["--arch", "mamba2-370m", "--no-smoke"]))
+    assert full.vocab_size == 50280 and full.d_model == 1024
+    assert model_config(parse_args(["--arch", "mamba2-370m"])).vocab_size \
+        == 256
+    assert model_config(parse_args(["--vocab", "128"])).vocab_size == 128
+
+
+def test_serve_bench_decode_measurement_failure_raises(monkeypatch):
+    """No constant stands in for a decode cadence that failed to measure;
+    --decode-ms stays the explicit pin."""
+    import repro.serve
+    from benchmarks import serve_bench
+
+    def no_device(*a, **k):
+        raise RuntimeError("no device")
+
+    monkeypatch.setattr(repro.serve, "measure_decode_s", no_device)
+    args = argparse.Namespace(decode_ms=0.0, decode_arch="deepseek-7b",
+                              decode_batch=8, decode_iters=1)
+    with pytest.raises(RuntimeError, match="no device"):
+        serve_bench.resolve_decode_s(args)
+    args.decode_ms = 2.0
+    assert serve_bench.resolve_decode_s(args) == (2e-3, "forced")
+
+
 def test_train_with_grad_compression():
     from repro.launch.train import run
     out = run(_train_args(steps=10, grad_compression=True))
