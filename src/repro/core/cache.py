@@ -275,15 +275,37 @@ class ClientCache:
                 self._flush_entry(e)
             e.valid = []
             e.dirty = []
-        elif old is None and tx is not None and e.dirty:
-            # non-tx write-back dirty bytes must NOT be adopted by the tx:
-            # once tagged, a later retag-away would flush them at the TX
-            # epoch (invisible until commit) and refill the page at the
-            # committed epoch — leaving a poisoned clean page no commit
-            # notification ever repairs.  Flush them at their natural auto
-            # epoch now, before the entry joins the tx.
-            self._flush_entry(e)
+        elif old is not None:
+            self._drop_shadowed(e, old.epoch)
+        elif tx is not None:
+            if e.dirty:
+                # non-tx write-back dirty bytes must NOT be adopted by the
+                # tx: once tagged, a later retag-away would flush them at
+                # the TX epoch (invisible until commit) and refill the page
+                # at the committed epoch — leaving a poisoned clean page no
+                # commit notification ever repairs.  Flush them at their
+                # natural auto epoch now, before the entry joins the tx.
+                self._flush_entry(e)
+            if e.valid:
+                # clean bytes fetched before the entry joins the tx predate
+                # what the tx already staged here: they are not its view
+                for name, offset, nbytes, _ in tx.write_log:
+                    if name == e.obj.name:
+                        _sub_interval(e.valid, offset, offset + nbytes)
         e.tx = tx
+
+    @staticmethod
+    def _drop_shadowed(e: _ObjEntry, epoch: int) -> None:
+        """The entry holds the view of the tx committed at ``epoch``.  A
+        record is a whole stripe cell, and where a write at a newer epoch
+        landed in a cell while the tx was open (this cache's own flush of
+        older dirty bytes among them), the committed view keeps that
+        record, not the tx's bytes: fetch those cells again."""
+        sc = e.obj.stripe_cell
+        cells = {c for a, b in e.valid for c in range(a // sc, -(-b // sc))}
+        for c in sorted(cells):
+            if e.obj.newest_epoch(c) > epoch:
+                _sub_interval(e.valid, c * sc, (c + 1) * sc)
 
     def _tx_bypass(self, e: _ObjEntry, tx, offset: int, nbytes: int) -> bool:
         """Reads under an OPEN transaction are snapshot-isolated at the tx

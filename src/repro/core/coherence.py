@@ -154,6 +154,13 @@ def _tx_sibling(entry, epoch) -> bool:
             and getattr(entry.tx, "epoch", None) == epoch)
 
 
+def _own_write(cache, entry, origin, epoch, replay: bool) -> bool:
+    """An event this cache's own flush caused.  A replayed tx write stays
+    its own only while the entry still holds that tx's view: once
+    retagged and refilled, its clean pages predate the tx's records."""
+    return origin is cache and (not replay or _tx_sibling(entry, epoch))
+
+
 class CoherencePolicy:
     """Decision surface between ``Container`` notifications and one
     ``ClientCache``'s read path.  One instance per cache (policies keep
@@ -168,7 +175,10 @@ class CoherencePolicy:
     # ---- container-side notifications ----
     def remote_write(self, cache, name: str, epoch: int, origin,
                      now: float, offset: int = 0, nbytes: int | None = None,
-                     ctx=None) -> None:
+                     ctx=None, replay: bool = False) -> None:
+        """``replay``: staged records of a transaction just became visible
+        (its commit, or the watermark passing it); nothing new was
+        written, and dirty bytes a cache holds are newer than them."""
         raise NotImplementedError
 
     @staticmethod
@@ -230,11 +240,9 @@ class BroadcastPolicy(CoherencePolicy):
     kind = "broadcast"
 
     def remote_write(self, cache, name, epoch, origin, now, offset=0,
-                     nbytes=None, ctx=None) -> None:
-        if origin is cache:
-            return
+                     nbytes=None, ctx=None, replay=False) -> None:
         entry = cache._entries.get(name)
-        if entry is None:
+        if entry is None or _own_write(cache, entry, origin, epoch, replay):
             return                   # not a sharer: no message to deliver
         if not cache.conflicts(entry, offset, nbytes):
             return                   # extent locks don't conflict: nothing
@@ -253,7 +261,10 @@ class BroadcastPolicy(CoherencePolicy):
         # time revocation opens a real stale window (the conformance
         # harness fails if this is "optimised" away)
         self._deliver(cache, ctx)
-        if cache.invalidate(name, offset, nbytes):
+        if replay:
+            # the clean pages are stale; unflushed bytes here still win
+            cache.trim_to_dirty(name, offset, nbytes)
+        elif cache.invalidate(name, offset, nbytes):
             self.stats.invalidations_applied += 1
 
 
@@ -287,12 +298,12 @@ class TimeoutPolicy(CoherencePolicy):
 
     # ---- notifications: bookkeeping only, no invalidation, no traffic ----
     def remote_write(self, cache, name, epoch, origin, now, offset=0,
-                     nbytes=None, ctx=None) -> None:
+                     nbytes=None, ctx=None, replay=False) -> None:
         entry = cache._entries.get(name)
         if entry is None:
             return
         pages = cache.pages_for(entry, offset, nbytes)
-        if origin is cache:
+        if _own_write(cache, entry, origin, epoch, replay):
             # our own flush landed: renew the remembered per-page versions
             # so expiry revalidation doesn't treat our own write as
             # foreign — but ONLY on pages with no foreign write pending.
@@ -361,12 +372,20 @@ class TimeoutPolicy(CoherencePolicy):
                                  process=ctx.process, engine=eng)
             dropped = False
             for p in expired:
-                if tokens[p] == entry.pver.get(p, -1):
-                    entry.lease[p] = now
-                    entry.pstale.pop(p, None)
-                else:
+                if tokens[p] != entry.pver.get(p, -1):
                     dropped = True
                     cache.invalidate(entry.obj.name, p * pg, pg)
+                elif p in entry.pstale:
+                    # the token held, yet a tx commit (or the watermark
+                    # passing an open tx) changed what readers see here
+                    # without moving any engine counter: drop the clean
+                    # bytes, keep the newer unflushed ones
+                    dropped = True
+                    cache.trim_to_dirty(entry.obj.name, p * pg, pg)
+                    for book in (entry.lease, entry.pver, entry.pstale):
+                        book.pop(p, None)
+                else:
+                    entry.lease[p] = now
             if dropped:
                 self.stats.reval_misses += 1
                 return False

@@ -29,6 +29,7 @@ class Container:
         self._overrides: dict[int, dict[int, int]] = {}  # oid -> {dead: new}
         self.snapshots: list[int] = []
         self._caches: list = []      # attached ClientCaches (coherence fan-out)
+        self._open_txs: list[Transaction] = []
 
     # ------------- epochs / transactions -------------
     @property
@@ -41,11 +42,32 @@ class Container:
     def auto_epoch(self) -> int:
         """Independent (non-tx) updates are immediately visible."""
         e = self.alloc_epoch()
-        self._committed = max(self._committed, e)
+        self._advance(e)
         return e
 
+    def _advance(self, epoch: int) -> None:
+        """Raise the committed watermark to ``epoch``.  It is a max, so
+        passing an open transaction's epoch makes the records that tx has
+        staged so far visible: replay them as coherence events, as its
+        commit would (caches that filled after the staging-time
+        notification still hold the bytes those records now shadow)."""
+        prev = self._committed
+        self._committed = max(prev, epoch)
+        for tx in list(self._open_txs):
+            if prev < tx.epoch <= self._committed:
+                self._replay_writes(tx)
+
+    def _replay_writes(self, tx: Transaction) -> None:
+        for name, offset, nbytes, ctx in tx.write_log:
+            self.notify_write(name, tx.epoch,
+                              origin=getattr(ctx, "cache", None),
+                              offset=offset, nbytes=nbytes, ctx=ctx,
+                              replay=True)
+
     def tx_begin(self) -> Transaction:
-        return Transaction(self)
+        tx = Transaction(self)
+        self._open_txs.append(tx)
+        return tx
 
     def commit_tx(self, tx: Transaction) -> None:
         # commit barrier: write-back data staged under this tx must reach
@@ -61,19 +83,22 @@ class Container:
             flush = getattr(c, "flush_tx", None)
             if flush is not None:
                 flush(tx)
-        self._committed = max(self._committed, tx.epoch)
+        self._forget(tx)
+        self._advance(tx.epoch)
         self.pool.raft.set(("cont_epoch", self.label), self._committed)
         # commit is when the staged bytes *change what readers see*: replay
         # the tx's write log as coherence events so foreign caches that
         # refetched pre-commit bytes during staging drop/destale them now
         # (sibling caches of this very tx hold the fresh bytes and are
         # exempted by the policies' _tx_sibling rule as usual)
-        for name, offset, nbytes, ctx in getattr(tx, "write_log", ()):
-            self.notify_write(name, tx.epoch,
-                              origin=getattr(ctx, "cache", None),
-                              offset=offset, nbytes=nbytes, ctx=ctx)
+        self._replay_writes(tx)
+
+    def _forget(self, tx: Transaction) -> None:
+        if tx in self._open_txs:
+            self._open_txs.remove(tx)
 
     def abort_tx(self, tx: Transaction) -> int:
+        self._forget(tx)
         # queued-but-unexecuted IODs never reach the engines: their bytes
         # belong to the epoch being punched (each completes with a
         # TxStateError so waiting callers learn the write was torn away)
@@ -97,6 +122,10 @@ class Container:
             eng = self.pool.engines[eid]
             if eng.alive:
                 dropped += eng.punch_epoch(tx.epoch)
+        if tx.epoch <= self._committed:
+            # the watermark had passed the tx: readers saw its records,
+            # and the punch just took them away again
+            self._replay_writes(tx)
         return dropped
 
     def snapshot(self) -> int:
@@ -122,7 +151,7 @@ class Container:
 
     def notify_write(self, name: str, epoch: int, origin=None,
                      offset: int = 0, nbytes: int | None = None,
-                     ctx=None) -> None:
+                     ctx=None, replay: bool = False) -> None:
         """Fan a write event out to every attached cache's policy.  The
         event carries the touched extent ``(offset, nbytes)`` (``nbytes``
         None = unknown: treat as the whole object) and the writer's
@@ -135,14 +164,18 @@ class Container:
         so staged records *leak* into the committed view the moment any
         later auto-epoch write lands — revoking at staging conservatively
         covers that window (the conformance harness catches real stale
-        serves if this is skipped), and the commit-time write-log replay
-        covers caches that refetched pre-commit bytes in between."""
+        serves if this is skipped), and the write-log replay at commit, or
+        when the watermark passes the open tx, covers caches that
+        refetched pre-commit bytes in between.  ``replay`` marks such a
+        replay: the records were already on the engines and only became
+        visible, so every write a cache still holds dirty is newer."""
         if not self._caches:
             return
         now = self.pool.sim.clock.now
         for c in list(self._caches):
             c.policy.remote_write(c, name, epoch, origin, now,
-                                  offset=offset, nbytes=nbytes, ctx=ctx)
+                                  offset=offset, nbytes=nbytes, ctx=ctx,
+                                  replay=replay)
 
     def notify_punch(self, name: str, origin=None, ctx=None) -> None:
         if not self._caches:

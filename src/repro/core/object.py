@@ -106,6 +106,14 @@ class ArrayObject(_ObjectBase):
         info for EC."""
         return self._planner(lay).cell_engines(cell_no)
 
+    def newest_epoch(self, cell_no: int) -> int:
+        """The newest epoch any live engine holds a record of data cell
+        ``cell_no`` at (0 if none), visible or still staged."""
+        key = self._key("arr", cell_no)
+        return max((max(eng.records(key), default=0)
+                    for eng in map(self._engine, set(self._layout().targets))
+                    if eng.alive), default=0)
+
     # ---------------- size metadata ----------------
     @property
     def size(self) -> int:

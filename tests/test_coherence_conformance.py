@@ -359,6 +359,31 @@ def test_conformance(fleet, seed):
     assert w.checked_reads > 0
 
 
+#: interleavings random draws once failed, pinned by cause: a write at a
+#: newer epoch in a cell the tx wrote hides its bytes after commit (a
+#: record is a whole cell); a commit or the watermark passing an open tx
+#: changes what readers see without moving any token; an abort takes away
+#: records the watermark had already shown; an entry retagged before its
+#: tx commits, or filled before it joins the tx, does not hold the tx's
+#: view.  (all-broadcast 982526257 pins the replay running mid-flush.)
+COUNTEREXAMPLES = [
+    ("all-broadcast", 276600181), ("all-broadcast", 24520513),
+    ("all-broadcast", 62534619), ("all-broadcast", 1282502805),
+    ("all-broadcast", 560025634), ("all-broadcast", 363698845),
+    ("all-broadcast", 982526257), ("all-broadcast", 1334828420),
+    ("all-broadcast", 1440983678), ("all-broadcast", 267798643),
+    ("all-timeout", 1634354142), ("mixed", 981658422),
+    ("mixed", 1628980321), ("mixed", 1775344344),
+    ("mixed-async", 2070305828), ("mixed-async", 1909209475),
+]
+
+
+@pytest.mark.parametrize("fleet,seed", COUNTEREXAMPLES)
+def test_conformance_counterexample(fleet, seed):
+    w = run_interleaving(fleet, seed)
+    assert w.checked_reads > 0
+
+
 def test_staleness_is_actually_exercised():
     """The harness must not pass vacuously: across the fixed-seed matrix,
     timeout fleets really do serve (legally) stale bytes sometimes, and
