@@ -1,0 +1,7 @@
+"""Host time of ``KVCacheStore.offload`` per return (ms)."""
+from lib.readers import mean_span_s
+
+
+def read(data):
+    s = mean_span_s(data, "offload")
+    return None if s is None else 1e3 * s
