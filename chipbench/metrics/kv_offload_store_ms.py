@@ -1,0 +1,7 @@
+"""Self time of the object and transaction layers (``obj.*``, ``tx.*``)
+under the program's ``kv.offload`` span, per offload (ms)."""
+from lib.program import mean_ms
+
+
+def read(data):
+    return mean_ms(data, "kv.offload", "store")

@@ -20,7 +20,7 @@ import json
 
 import numpy as np
 
-from ..core import integrity
+from ..core import integrity, obs
 from ..kernels import ops as kops
 
 
@@ -54,9 +54,13 @@ def _unflatten_at(items, template, prefix):
 
 
 def leaf_to_bytes(leaf) -> tuple[np.ndarray, dict]:
-    arr = np.asarray(leaf)
-    meta = {"shape": list(arr.shape), "dtype": str(arr.dtype)}
-    raw = np.ascontiguousarray(arr).view(np.uint8).reshape(-1)
+    """A leaf's bytes on the host (a device leaf is copied here) and its
+    shape and dtype."""
+    with obs.span("ser.to_host") as sp:
+        arr = np.asarray(leaf)
+        meta = {"shape": list(arr.shape), "dtype": str(arr.dtype)}
+        raw = np.ascontiguousarray(arr).view(np.uint8).reshape(-1)
+        sp["nbytes"] = int(raw.size)
     return raw, meta
 
 

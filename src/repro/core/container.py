@@ -11,6 +11,7 @@ from __future__ import annotations
 import itertools
 
 from . import layout as _layout
+from . import obs
 from .object import ArrayObject, KVObject
 from .transactions import Transaction
 
@@ -69,6 +70,7 @@ class Container:
         self._open_txs.append(tx)
         return tx
 
+    @obs.span("tx.commit")
     def commit_tx(self, tx: Transaction) -> None:
         # commit barrier: write-back data staged under this tx must reach
         # the engines BEFORE the epoch becomes visible.  A client crash

@@ -20,6 +20,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from . import obs
+
 WEIGHT = np.uint32(2654435761)
 _MASK64 = (1 << 64) - 1
 
@@ -61,12 +63,14 @@ def weight_powers(n: int, start_power: int = 1) -> np.ndarray:
 
 def checksum(data) -> int:
     """Weighted-word checksum of a bytes-like / ndarray. Returns python int."""
-    words, nbytes = _as_u32_words(data)
-    with np.errstate(over="ignore"):
-        acc = np.uint32(0)
-        if words.size:
-            w = weight_powers(words.size)
-            acc = np.sum(w * words, dtype=np.uint32)
+    with obs.span("integrity.checksum") as sp:
+        words, nbytes = _as_u32_words(data)
+        sp["nbytes"] = nbytes
+        with np.errstate(over="ignore"):
+            acc = np.uint32(0)
+            if words.size:
+                w = weight_powers(words.size)
+                acc = np.sum(w * words, dtype=np.uint32)
     return int(acc) ^ (_splitmix64(nbytes) & 0xFFFFFFFF)
 
 

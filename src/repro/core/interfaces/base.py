@@ -18,6 +18,7 @@ import dataclasses
 
 import numpy as np
 
+from .. import obs
 from ..cache import ClientCache
 from ..coherence import make_policy, normalize_coherence
 from ..events import QueuedOp, SubmissionQueue
@@ -152,6 +153,7 @@ class FileHandle:
             return bytes(data)
         return np.ascontiguousarray(data).view(np.uint8).reshape(-1).copy()
 
+    @obs.span("obj.submit")
     def write_at_async(self, offset: int, data) -> QueuedOp:
         buf = self._snapshot(data)
         return self._subq().submit(

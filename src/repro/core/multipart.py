@@ -25,6 +25,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from . import obs
+
 MIB = 1 << 20
 
 #: transfers at or above this size take the multipart path
@@ -66,6 +68,7 @@ def _fan_handles(iface, parts, open_first, placer, tx=None):
     return handles
 
 
+@obs.span("obj.multipart")
 def multipart_read(iface, path: str, nbytes: int, *, offset: int = 0,
                    part_bytes: int = MP_PART_BYTES,
                    placer=None) -> np.ndarray:
@@ -86,6 +89,7 @@ def multipart_read(iface, path: str, nbytes: int, *, offset: int = 0,
     return out
 
 
+@obs.span("obj.multipart")
 def multipart_write_at(iface, handle, offset: int, data, *, tx=None,
                        part_bytes: int = MP_PART_BYTES,
                        placer=None) -> int:

@@ -21,6 +21,7 @@ import dataclasses
 import numpy as np
 
 from . import layout as _layout
+from . import obs
 from . import redundancy
 from .engine import Engine, EngineFailedError, NotFoundError
 from .events import SubmissionQueue
@@ -124,6 +125,7 @@ class ArrayObject(_ObjectBase):
                                        max(self.size, new_end))
 
     # ---------------- write ----------------
+    @obs.span("obj.array_write")
     def write(self, offset: int, data, epoch: int | None = None,
               ctx: IOCtx = DEFAULT_CTX) -> int:
         """Write bytes at offset. Returns bytes written."""
@@ -311,6 +313,7 @@ class ArrayObject(_ObjectBase):
             nbytes=n_recon * plan.data_width() * self.stripe_cell,
             nops=n_recon)
 
+    @obs.span("obj.array_read")
     def read(self, offset: int, size: int, epoch: float | None = None,
              ctx: IOCtx = DEFAULT_CTX) -> np.ndarray:
         """Read bytes [offset, offset+size) visible at the snapshot epoch.
@@ -440,6 +443,7 @@ class KVObject(_ObjectBase):
     def _shard_for(self, dkey) -> int:
         return self._planner().kv_shard(dkey)
 
+    @obs.span("obj.kv")
     def put(self, dkey, akey, value, epoch: int | None = None,
             ctx: IOCtx = DEFAULT_CTX) -> None:
         if epoch is None:
@@ -460,6 +464,7 @@ class KVObject(_ObjectBase):
                 from last_err
         self._record_flows(acc.flows(batch=False), "write", ctx)
 
+    @obs.span("obj.kv")
     def get(self, dkey, akey, epoch: float | None = None,
             ctx: IOCtx = DEFAULT_CTX) -> bytes:
         if epoch is None:
@@ -617,6 +622,7 @@ class KVBatch:
             fn = lambda: self._exec_put(o, dkey, akey, raw, engines)
         return self._sq.submit(fn, engines)
 
+    @obs.span("obj.kv")
     def _exec_put(self, o: KVObject, dkey, akey, raw: bytes,
                   engines) -> int:
         epoch = (self.tx.epoch if self.tx is not None
@@ -648,6 +654,7 @@ class KVBatch:
             fn = lambda: self._exec_get(o, dkey, akey, engines)
         return self._sq.submit(fn, engines[:1])
 
+    @obs.span("obj.kv")
     def _exec_get(self, o: KVObject, dkey, akey, engines) -> bytes:
         epoch = (float(self.tx.epoch) if self.tx is not None
                  else float(o.container.committed_epoch))

@@ -52,7 +52,7 @@ import json
 
 import numpy as np
 
-from ..core import NotFoundError
+from ..core import NotFoundError, obs
 from ..core.interfaces import AccessInterface, DFS, make_interface
 from ..core.multipart import MP_THRESHOLD, multipart_read, should_multipart
 from ..ckpt import serializer as S
@@ -199,6 +199,13 @@ class KVCacheStore:
         Re-offloading an existing session (a new ``step``) overwrites its
         leaves in place — through the object layer, so attached reader
         caches hear about every update via their coherence policy."""
+        with obs.span("kv.offload", session=str(session)) as sp:
+            out = self._offload(session, cache, step, extra_meta)
+            sp["nbytes"] = sum(e["nbytes"] for e in out["leaves"].values())
+        return out
+
+    def _offload(self, session: str, cache, step: int,
+                 extra_meta: dict | None) -> dict:
         cont = self.dfs.cont
         sdir = self._sess_dir(session)
         try:
@@ -291,6 +298,14 @@ class KVCacheStore:
         decode path pays leaf reads, not a manifest walk per step.
         A demoted session promotes back to the hot tier first (through
         the async data path), transparently."""
+        with obs.span("kv.restore", session=str(session)) as sp:
+            tree = self._restore(session, client_node, process, man)
+            sp["nbytes"] = sum(int(leaf.nbytes)
+                               for _, leaf in S.flatten_tree(tree))
+        return tree
+
+    def _restore(self, session: str, client_node: int | None,
+                 process: int | None, man: dict | None):
         man = self._hot_manifest(session, man)
         items: dict = {}
         for path, entry in man["leaves"].items():
