@@ -13,8 +13,11 @@ it order-sensitive (unlike a plain sum) and the form is *tile-decomposable*:
 
 which is exactly what the Pallas kernel in ``repro.kernels.checksum`` exploits
 to compute it on-device with (8,128) VMEM tiles.  This module is the host-side
-numpy implementation; ``tests/test_kernels.py`` asserts all three (numpy,
-ref.py jnp oracle, Pallas interpret) agree bit-for-bit.
+numpy implementation and uses the same decomposition with T = 2^18 words (one
+1 MiB stripe cell): the weights W^1..W^T are built once, at import, and every
+input of any length is summed against them a block at a time.
+``tests/test_kernels.py`` asserts all three (numpy, ref.py jnp oracle, Pallas
+interpret) agree bit-for-bit.
 """
 from __future__ import annotations
 
@@ -23,6 +26,7 @@ import numpy as np
 from . import obs
 
 WEIGHT = np.uint32(2654435761)
+_MASK32 = (1 << 32) - 1
 _MASK64 = (1 << 64) - 1
 
 
@@ -33,17 +37,18 @@ def _splitmix64(x: int) -> int:
     return x ^ (x >> 31)
 
 
-def _as_u32_words(data) -> tuple[np.ndarray, int]:
-    """View arbitrary bytes as little-endian uint32 words (zero padded)."""
+def _as_u32_words(data) -> tuple[np.ndarray, int, int]:
+    """View arbitrary bytes as little-endian uint32 words: the whole words,
+    the last 1-3 bytes zero padded into one more word (0 if none), and the
+    byte length."""
     if isinstance(data, np.ndarray):
         buf = np.ascontiguousarray(data).view(np.uint8).reshape(-1)
     else:
         buf = np.frombuffer(bytes(data), dtype=np.uint8)
     n = buf.size
-    pad = (-n) % 4
-    if pad:
-        buf = np.concatenate([buf, np.zeros(pad, np.uint8)])
-    return buf.view("<u4"), n
+    whole = n - n % 4
+    tail = int.from_bytes(buf[whole:].tobytes(), "little")
+    return buf[:whole].view("<u4"), tail, n
 
 
 def weight_powers(n: int, start_power: int = 1) -> np.ndarray:
@@ -61,17 +66,38 @@ def weight_powers(n: int, start_power: int = 1) -> np.ndarray:
     return out
 
 
+BLOCK_WORDS = 1 << 18
+# W^1..W^T, built once and never written after: concurrent callers (the
+# checkpointer's save threads) only read it.
+_TABLE = weight_powers(BLOCK_WORDS)
+_TABLE.flags.writeable = False
+_W_BLOCK = pow(int(WEIGHT), BLOCK_WORDS, 1 << 32)
+
+
+def _weighted_sum(words: np.ndarray) -> int:
+    """sum_i W^(i+1) * words[i] mod 2^32, one block of T words at a time:
+    block b adds W^(b*T) * sum(block_b * W^1..W^T)."""
+    scratch = np.empty(min(words.size, BLOCK_WORDS), np.uint32)
+    acc, shift = 0, 1
+    for lo in range(0, words.size, BLOCK_WORDS):
+        block = words[lo:lo + BLOCK_WORDS]
+        part = np.multiply(block, _TABLE[:block.size],
+                           out=scratch[:block.size])
+        acc = (acc + shift * int(part.sum(dtype=np.uint32))) & _MASK32
+        shift = (shift * _W_BLOCK) & _MASK32
+    return acc
+
+
 def checksum(data) -> int:
     """Weighted-word checksum of a bytes-like / ndarray. Returns python int."""
     with obs.span("integrity.checksum") as sp:
-        words, nbytes = _as_u32_words(data)
+        words, tail, nbytes = _as_u32_words(data)
         sp["nbytes"] = nbytes
-        with np.errstate(over="ignore"):
-            acc = np.uint32(0)
-            if words.size:
-                w = weight_powers(words.size)
-                acc = np.sum(w * words, dtype=np.uint32)
-    return int(acc) ^ (_splitmix64(nbytes) & 0xFFFFFFFF)
+        acc = _weighted_sum(words)
+        if tail:
+            acc = (acc + pow(int(WEIGHT), words.size + 1, 1 << 32) * tail) \
+                & _MASK32
+    return acc ^ (_splitmix64(nbytes) & 0xFFFFFFFF)
 
 
 class ChecksumError(IOError):
