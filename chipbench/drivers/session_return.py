@@ -96,7 +96,9 @@ def run(ctx) -> dict:
         spec = jax.eval_shape(
             lambda: models.init_model(jax.random.PRNGKey(0), cfg))
         layout = weights.layout_of(spec)
-        params = weights.make(jax_key(ctx.seed, 1), layout)
+        params = weights.make(jax_key(ctx.seed, 1), layout,
+                              ctx.config["program"]["family"],
+                              ctx.cell.bench_dir)
         jax.block_until_ready(params)
 
     pool = Pool(Topology())
@@ -253,7 +255,9 @@ def answer_gap(ctx, layout, hist, user, answers, finished, U, A,
     picks = sample_turns(finished, hist, U, A, tr["check_turns"], ctx.seed)
     if not picks:
         return NO_READING
-    params = weights.make(jax_key(ctx.seed, 1), layout)
+    params = weights.make(jax_key(ctx.seed, 1), layout,
+                          ctx.config["program"]["family"],
+                          ctx.cell.bench_dir)
     worst = 0.0
     for i, turn in picks:
         seq, positions = turn_tokens(hist, user, answers, i, turn, U, A)

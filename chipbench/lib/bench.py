@@ -5,9 +5,12 @@ A cell names a configuration and a traffic mix.  The configuration is
 ``chipbench/refs/<reference>.py``), the traffic mix is
 ``chipbench/traffic/<traffic>.json`` and names the driver that runs it,
 ``chipbench/drivers/<driver>.py``; the limits its comparison with the
-reference is held to are ``chipbench/limits/<cell>.json``; and each
-per-layer metric is read by ``chipbench/metrics/<metric>.py``.  Adding any of these is adding files
-and entries; nothing here changes.
+reference is held to are ``chipbench/limits/<cell>.json``; each
+per-layer metric is read by ``chipbench/metrics/<metric>.py``; and the
+configuration's ``program.family`` names ``chipbench/families/<family>.py``,
+which counts its operations and bytes (``lib/counts.py``) and may add
+rules for its weights (``lib/weights.py``).  Adding any of these is
+adding files and entries; nothing here changes.
 """
 from __future__ import annotations
 
@@ -56,6 +59,7 @@ class Cell:
     end_to_end: list
     per_layer: list
     limits: dict
+    bench_dir: pathlib.Path = BENCH_DIR     # where its family file is
 
     @property
     def driver(self):
@@ -82,17 +86,25 @@ def cell(bench: dict, name: str, bench_dir: pathlib.Path = BENCH_DIR) -> Cell:
     if not conf:
         raise BenchError(f"workload {name!r} names no known config")
     config = read_json(ROOT / conf[0]["file"])
+    # an unknown family is refused here, before any weights or compiles
+    family(config["program"]["family"], bench_dir)
     traffic = read_json(bench_dir / "traffic" / f"{w['traffic']}.json")
     limits = read_json(bench_dir / "limits" / f"{name}.json")
     return Cell(
         name=name, chips=int(w["chips"]), config=config, traffic=traffic,
-        limits=limits,
+        limits=limits, bench_dir=bench_dir,
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, name)])
 
 
 def metric_reader(name: str, bench_dir: pathlib.Path = BENCH_DIR):
     return load_module(bench_dir / "metrics" / f"{name}.py")
+
+
+def family(family: str, bench_dir: pathlib.Path = BENCH_DIR):
+    """The module of an architecture family, found by its name."""
+    return load_module(bench_dir / "families" / f"{family}.py",
+                       "chipbench_family_" + family)
 
 
 def program_config(config: dict):

@@ -4,60 +4,31 @@ These are what a roofline share or an MFU divides by: the work the
 algorithm requires, computed from the configuration file alone, never
 from the program's compiled graph (a program that does extra work shows
 a lower share, which is the point).
+
+A configuration's ``program.family`` names the file
+``chipbench/families/<family>.py`` that counts for it.  Its class
+``Counts(config)`` gives what the per-layer readers call:
+
+- ``n_params`` and ``matmul_params``: all weights, and those that enter
+  a matrix product (the head among them);
+- ``cache_bytes(slots, batch=1)``: one session's cache at ``slots``
+  positions;
+- ``decode_flops(slots, batch=1)`` and ``decode_bytes(slots, batch=1)``:
+  the operations and the HBM bytes of one decode step against ``slots``
+  cached positions.
 """
 from __future__ import annotations
+
+import pathlib
+
+from . import bench as B
 
 BF16 = 2
 
 
-def _vocab(c: dict) -> int:
+def vocab(c: dict) -> int:
     return int(c.get("padded_vocab_size", c["vocab_size"]))
 
 
-class Dense:
-    """A decoder-only transformer with GQA and a SwiGLU MLP."""
-
-    def __init__(self, c: dict) -> None:
-        self.L = c["num_hidden_layers"]
-        self.d = c["hidden_size"]
-        self.ff = c["intermediate_size"]
-        self.hq = c["num_attention_heads"]
-        self.hkv = c["num_key_value_heads"]
-        self.hd = c["head_dim"]
-        self.V = _vocab(c)
-
-    @property
-    def layer_matmul_params(self) -> int:
-        d, q, kv = self.d, self.hq * self.hd, self.hkv * self.hd
-        return d * q + 2 * d * kv + q * d + 3 * d * self.ff
-
-    @property
-    def matmul_params(self) -> int:
-        return self.L * self.layer_matmul_params + self.d * self.V
-
-    @property
-    def n_params(self) -> int:
-        return (self.L * (self.layer_matmul_params + 2 * self.d)
-                + 2 * self.V * self.d + self.d)
-
-    def cache_bytes(self, slots: int, batch: int = 1) -> int:
-        return self.L * batch * slots * self.hkv * self.hd * 2 * BF16
-
-    def decode_flops(self, slots: int, batch: int = 1) -> int:
-        """One token per sequence against ``slots`` cached positions."""
-        attn = 2 * 2 * self.L * self.hq * self.hd * slots
-        return batch * (2 * self.matmul_params + attn)
-
-    def decode_bytes(self, slots: int, batch: int = 1) -> int:
-        """Every weight once, the whole cache read, one slot written."""
-        weights = (self.n_params - self.V * self.d) * BF16 \
-            + batch * self.d * BF16
-        write = self.L * batch * self.hkv * self.hd * 2 * BF16
-        return weights + self.cache_bytes(slots, batch) + write
-
-
-FAMILIES = {"dense": Dense}
-
-
-def counts_for(config: dict):
-    return FAMILIES[config["program"]["family"]](config)
+def counts_for(config: dict, bench_dir: pathlib.Path = B.BENCH_DIR):
+    return B.family(config["program"]["family"], bench_dir).Counts(config)

@@ -85,7 +85,7 @@ class LayerData:
     def __init__(self, ctx, out, kind):
         self.rec, self.summary = ctx.rec, ctx.summary
         self.extras = out.get("extras", {})
-        self.counts = counts.counts_for(ctx.config)
+        self.counts = counts.counts_for(ctx.config, ctx.cell.bench_dir)
         self.peaks = peaks.peaks(kind)
         self.cell = ctx.cell
 

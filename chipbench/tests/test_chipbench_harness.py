@@ -83,6 +83,7 @@ def test_a_new_cell_and_metric_are_found_by_name(tmp_path):
     entries only, are found by the names in BENCHMARK.json."""
     for d in ("traffic", "metrics", "limits"):
         (tmp_path / d).mkdir()
+    shutil.copytree(BENCH / "families", tmp_path / "families")
     (tmp_path / "limits" / "dummy-cell.json").write_text(
         json.dumps({"answer_gap_max": 0.5}))
     base = json.loads((BENCH / "traffic" / "session_return.json").read_text())
@@ -104,6 +105,7 @@ def test_a_new_cell_and_metric_are_found_by_name(tmp_path):
             m["workloads"].append("dummy-cell")
     cell = B.cell(bench, "dummy-cell", bench_dir=tmp_path)
     assert cell.traffic["rate_per_s"] == 0.1
+    assert cell.bench_dir == tmp_path   # its family is loaded from there
     assert cell.driver.__name__.endswith("session_return")
     assert [m["name"] for m in cell.per_layer] == ["dummy.metric"]
     assert {m["name"] for m in cell.end_to_end} == {
@@ -118,15 +120,20 @@ def test_peaks_table_refuses_an_unknown_device():
         peaks.peaks("cpu")
 
 
-@pytest.mark.parametrize("name", ["h2o-danube-1.8b"])
+CONFIGS = {c["name"]: c["file"] for c in B.load_benchmark()["configs"]}
+
+
+@pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_counts_agree_with_the_program(name):
-    """Parameter counts and cache bytes from the configuration file alone
-    agree with the program's own shapes at the published sizes."""
+    """Parameter counts and cache bytes from the configuration file alone,
+    by the family file it names, agree with the program's own shapes at
+    the published sizes.  Every configuration in ``BENCHMARK.json`` is a
+    case, so every family file that one names is checked."""
     import jax
     import numpy as np
 
     import repro.models as models
-    config = B.read_json(BENCH / "configs" / f"{name}.json")
+    config = B.read_json(ROOT / CONFIGS[name])
     cfg = B.program_config(config)
     c = counts.counts_for(config)
     spec = jax.eval_shape(lambda: models.init_model(jax.random.PRNGKey(0),
