@@ -9,7 +9,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Literal
 
-Family = Literal["dense", "encdec", "vlm", "hybrid", "moe", "ssm", "audio"]
+Family = Literal["dense", "encdec", "vlm", "hybrid", "moe", "ssm", "audio",
+                 "hybrid_ssd"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,6 +28,9 @@ class ModelConfig:
     rope_theta: float = 10000.0
     rotary_pct: float = 1.0
     swa_window: int = 0              # sliding-window attention; 0 = full
+    attn_scale: float = 0.0          # score multiplier; 0 = 1/sqrt(head_dim)
+    nope: bool = False               # no positional encoding (with
+    #                                  rotary_pct 0: no sinusoids either)
     mlp: str = "swiglu"              # swiglu | geglu | gelu
     # enc-dec
     enc_layers: int = 0
@@ -43,11 +47,22 @@ class ModelConfig:
     lru_width: int = 0
     local_window: int = 0
     conv_width: int = 4
+    # hybrid_ssd (granite-4.0-h): SSD mixers with an attention mixer at
+    # every layer i with i % attn_period == attn_offset
+    attn_period: int = 0
+    attn_offset: int = 0
     # ssm (mamba2 / SSD)
     ssm_state: int = 0
     ssm_headdim: int = 64
     ssm_expand: int = 2
     ssm_chunk: int = 256
+    conv_bias: bool = False          # a bias on the SSD causal conv
+    # multipliers (granite): token embedding, each sub-layer's output
+    # before the residual add, and the divisor of the logits
+    embed_mult: float = 1.0
+    residual_mult: float = 1.0
+    logits_scaling: float = 1.0
+    tie_embeddings: bool = False     # the LM head is the token embedding
     # numerics / training
     param_dtype: str = "bfloat16"
     optimizer: str = "adamw"         # adamw | adafactor
@@ -91,6 +106,17 @@ class ModelConfig:
         d, V = self.d_model, self.padded_vocab()
         emb = V * d
         per_layer = 0
+        if self.family == "hybrid_ssd":
+            din = self.ssm_expand * d
+            H, N = self.ssm_heads, self.ssm_state
+            xbc = din + 2 * N
+            ssd = (d * (din + xbc + H) + self.conv_width * xbc
+                   + xbc * self.conv_bias + 3 * H + din + din * d)
+            attn = 2 * d * self.q_dim + 2 * d * self.kv_dim
+            n_attn = self.n_layers // self.attn_period
+            blocks = (n_attn * attn + (self.n_layers - n_attn) * ssd
+                      + self.n_layers * (3 * d * self.d_ff + 2 * d))
+            return emb * (1 if self.tie_embeddings else 2) + blocks + d
         if self.family == "ssm":
             din = self.ssm_expand * d
             # in_proj (z,x,B,C,dt) + conv + out_proj + A,D,norm
@@ -178,6 +204,11 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
         changes.update(attn_every=3, lru_width=64, local_window=32)
     if cfg.family == "ssm":
         changes.update(ssm_state=16, ssm_headdim=16, ssm_chunk=8)
+    if cfg.family == "hybrid_ssd":
+        # two super-blocks of (SSD, attention, SSD): both kinds of layer
+        changes.update(n_layers=6, attn_period=3, attn_offset=1,
+                       n_kv_heads=2, ssm_state=16, ssm_headdim=16,
+                       ssm_chunk=8)
     if cfg.n_prefix_tokens:
         changes.update(n_prefix_tokens=4)
     if cfg.swa_window:

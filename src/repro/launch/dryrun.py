@@ -147,6 +147,8 @@ def attention_kernel_ideal_bytes(cfg, shape, mesh) -> dict | None:
         n_attn = cfg.enc_layers + 2 * cfg.dec_layers
     elif cfg.family == "hybrid":
         n_attn = cfg.n_layers // cfg.attn_every
+    elif cfg.family == "hybrid_ssd":
+        n_attn = cfg.n_layers // cfg.attn_period
     elif cfg.family == "ssm":
         return {"add_bytes": 0.0}
     else:

@@ -39,18 +39,20 @@ def _block_mask(qi0, ki0, bq, bk, *, causal: bool, window: int,
     return jnp.where(allow, 0.0, NEG)
 
 
-def _attend_block(q, k, v, mask):
+def _attend_block(q, k, v, mask, scale=None):
     """q: (B,Hkv,G,bq,D), k/v: (B,Hkv,bk,D), mask: (bq,bk).
-    Returns (scores_exp (..bq,bk) style partials): m, l, acc contribution."""
+    Returns (scores_exp (..bq,bk) style partials): m, l, acc contribution.
+    Scores are scaled by ``scale``, else by 1/sqrt(D)."""
     s = jnp.einsum("bhgqd,bhkd->bhgqk", q, k,
                    preferred_element_type=jnp.float32)
-    s = s / np.sqrt(q.shape[-1]) + mask
-    return s
+    s = s / np.sqrt(q.shape[-1]) if scale is None else s * scale
+    return s + mask
 
 
 def blockwise_attention(q, k, v, n_kv: int, *, causal: bool = True,
                         window: int = 0, prefix: int = 0,
-                        bq: int = 256, bk: int = 512) -> jnp.ndarray:
+                        bq: int = 256, bk: int = 512,
+                        scale: float | None = None) -> jnp.ndarray:
     """q: (B,S,Hq,D); k,v: (B,Sk,Hkv,D) -> (B,S,Hq,D).  fp32 accumulators."""
     B, S, Hq, D = q.shape
     Sk = k.shape[1]
@@ -78,12 +80,12 @@ def blockwise_attention(q, k, v, n_kv: int, *, causal: bool = True,
             ki0, kblk, vblk = inp
             mask = _block_mask(qi * bq, ki0, bq, kblk.shape[-2],
                                causal=causal, window=window, prefix=prefix)
-            s = _attend_block(qblk, kblk, vblk, mask)
+            s = _attend_block(qblk, kblk, vblk, mask, scale)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            scale = jnp.exp(m - m_new)
+            rescale = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new[..., None])
-            l = l * scale + jnp.sum(p, axis=-1)
-            acc = acc * scale[..., None] + jnp.einsum(
+            l = l * rescale + jnp.sum(p, axis=-1)
+            acc = acc * rescale[..., None] + jnp.einsum(
                 "bhgqk,bhkd->bhgqd", p.astype(vblk.dtype), vblk
             ).astype(jnp.float32)
             return (m_new, l, acc), None
@@ -101,7 +103,7 @@ def blockwise_attention(q, k, v, n_kv: int, *, causal: bool = True,
                 vfull, (0, 0, start, 0), (B, n_kv, span, D))
             mask = _block_mask(qi * bq, start, bq, span, causal=causal,
                                window=window, prefix=prefix)
-            s = _attend_block(qblk, ksl, vsl, mask)
+            s = _attend_block(qblk, ksl, vsl, mask, scale)
             m = jnp.max(s, axis=-1)
             p = jnp.exp(s - m[..., None])
             l = jnp.sum(p, axis=-1)

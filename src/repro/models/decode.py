@@ -41,6 +41,17 @@ def cache_spec(cfg, seq_len: int, batch: int, tp_pad: int = 1):
             "conv": sds((cfg.n_layers, batch, cfg.conv_width - 1,
                          din + 2 * cfg.ssm_state), dt),
         }
+    if cfg.family == "hybrid_ssd":
+        n_attn = T.block_kinds(cfg).count("attn")
+        din = cfg.ssm_expand * cfg.d_model
+        kv = sds((n_attn, batch, Lc, cfg.n_kv_heads, cfg.head_dim), dt)
+        return {
+            "state": sds((cfg.n_layers - n_attn, batch, cfg.ssm_heads,
+                          cfg.ssm_state, cfg.ssm_headdim), jnp.float32),
+            "conv": sds((cfg.n_layers - n_attn, batch, cfg.conv_width - 1,
+                         din + 2 * cfg.ssm_state), dt),
+            "k": kv, "v": kv,
+        }
     if cfg.family == "hybrid":
         kinds = T.block_kinds(cfg)
         n_attn = sum(1 for k in kinds if k == "local_attn")
@@ -86,8 +97,8 @@ def forward_decode(params: Params, cfg, cache: dict, tokens: jnp.ndarray,
     """tokens: (B, 1) int32; pos: scalar int32 (current position).
     Returns (hidden (B, 1, d), cache')."""
     n_heads = T.params_n_heads(params, cfg)
-    x = L.embed(params["embed"], tokens)
-    if cfg.rotary_pct == 0.0 and cfg.family != "ssm":
+    x = L.embed(params["embed"], tokens, cfg.embed_mult)
+    if cfg.rotary_pct == 0.0 and cfg.family != "ssm" and not cfg.nope:
         B = x.shape[0]
         posv = jnp.broadcast_to(pos[None, None], (B, 1))
         x = x + T._sinusoidal(posv, cfg.d_model).astype(x.dtype)
@@ -104,6 +115,9 @@ def forward_decode(params: Params, cfg, cache: dict, tokens: jnp.ndarray,
 
     if cfg.family == "hybrid":
         return _hybrid_decode(params, cfg, cache, x, pos, n_heads)
+
+    if cfg.family == "hybrid_ssd":
+        return _hybrid_ssd_decode(params, cfg, cache, x, pos, n_heads)
 
     if cfg.family == "encdec":
         return _encdec_decode(params, cfg, cache, x, pos, n_heads)
@@ -178,6 +192,25 @@ def _hybrid_decode(params, cfg, cache, x, pos, n_heads):
     return x, {"rec_h": rh_flat, "rec_conv": rc_flat, "k": k2, "v": v2}
 
 
+def _hybrid_ssd_decode(params, cfg, cache, x, pos, n_heads):
+    def ssd_fn(xx, p, c):
+        h = L.rms_norm(xx, p["norm1"])
+        y, state, conv = S.ssd_decode_step(p["ssm"], h, cfg, *c)
+        xx, _ = T._apply_mlp_or_moe(p, T._residual(xx, y, cfg), cfg)
+        return xx, (state, conv)
+
+    def attn_fn(xx, p, c):
+        h = L.rms_norm(xx, p["norm1"])
+        out, k, v = L.attention_decode(p["attn"], h, cfg, *c, pos, n_heads)
+        xx, _ = T._apply_mlp_or_moe(p, T._residual(xx, out, cfg), cfg)
+        return xx, (k, v)
+
+    x, ((state, conv), (k, v)) = T.hybrid_ssd_stack(
+        params, cfg, x, ssd_fn, attn_fn, rec=(cache["state"], cache["conv"]),
+        kv=(cache["k"], cache["v"]))
+    return x, {"state": state, "conv": conv, "k": k, "v": v}
+
+
 def _encdec_decode(params, cfg, cache, x, pos, n_heads):
     def step(xx, inp):
         lp, ck, cv, xk, xv = inp
@@ -239,6 +272,10 @@ def forward_prefill(params: Params, cfg, batch, pad_to: int | None = None):
 
     if cfg.family == "hybrid":
         return _hybrid_prefill(params, cfg, x, positions, n_heads, pad_to)
+
+    if cfg.family == "hybrid_ssd":
+        return T.hybrid_ssd_full(params, cfg, x, positions, n_heads,
+                                 pad_to=pad_to)
 
     Lc = cache_len(cfg, max(x.shape[1], pad_to))
 

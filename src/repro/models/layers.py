@@ -196,15 +196,16 @@ def _split_heads(x, n_heads, head_dim):
     return x.reshape(*x.shape[:-1], n_heads, head_dim)
 
 
-def gqa_scores_softmax_v(q, k, v, mask, n_kv):
+def gqa_scores_softmax_v(q, k, v, mask, n_kv, scale=None):
     """q: (B,Sq,Hq,D), k/v: (B,Sk,Hkv,D). Returns (B,Sq,Hq,D).
-    Hq % Hkv == 0; groups broadcast."""
+    Hq % Hkv == 0; groups broadcast.  Scores are scaled by ``scale``,
+    else by 1/sqrt(D)."""
     B, Sq, Hq, D = q.shape
     G = Hq // n_kv
     qg = q.reshape(B, Sq, n_kv, G, D)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                         preferred_element_type=jnp.float32)
-    scores = scores / np.sqrt(D)
+    scores = scores / np.sqrt(D) if scale is None else scores * scale
     if mask is not None:
         scores = scores + mask
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
@@ -253,7 +254,7 @@ def attention_decode(params: Params, x: jnp.ndarray, cfg,
     mask = jnp.where(valid, 0.0, -1e30).astype(jnp.float32)[None, None, None]
     out = gqa_scores_softmax_v(q, cache_k.astype(q.dtype),
                                cache_v.astype(q.dtype), mask,
-                               cfg.n_kv_heads)
+                               cfg.n_kv_heads, cfg.attn_scale or None)
     return out.reshape(B, 1, -1) @ params["wo"], cache_k, cache_v
 
 
@@ -285,15 +286,27 @@ def init_embedding(key, cfg) -> Params:
     V = cfg.padded_vocab()
     dt = _dtype(cfg)
     k1, k2 = jax.random.split(key)
-    return {"tok": _init(k1, (V, cfg.d_model), scale=0.02, dtype=dt),
-            "head": _init(k2, (cfg.d_model, V), dtype=dt),
-            "final_norm": jnp.ones((cfg.d_model,), dt)}
+    p = {"tok": _init(k1, (V, cfg.d_model), scale=0.02, dtype=dt),
+         "final_norm": jnp.ones((cfg.d_model,), dt)}
+    if not cfg.tie_embeddings:
+        p["head"] = _init(k2, (cfg.d_model, V), dtype=dt)
+    return p
 
 
-def embed(params: Params, tokens: jnp.ndarray) -> jnp.ndarray:
-    return params["tok"][tokens]
+def embed(params: Params, tokens: jnp.ndarray,
+          mult: float = 1.0) -> jnp.ndarray:
+    x = params["tok"][tokens]
+    return x if mult == 1.0 else x * mult
 
 
-def lm_logits(params: Params, x: jnp.ndarray) -> jnp.ndarray:
+def head_weight(params: Params) -> jnp.ndarray:
+    """(d, V): the LM head, or the token embedding where it is tied."""
+    return params["head"] if "head" in params else params["tok"].T
+
+
+def lm_logits(params: Params, x: jnp.ndarray,
+              scaling: float = 1.0) -> jnp.ndarray:
+    """Logits of the final-normed ``x``, divided by ``scaling``."""
     x = rms_norm(x, params["final_norm"])
-    return x @ params["head"]
+    logits = x @ head_weight(params)
+    return logits if scaling == 1.0 else logits / scaling

@@ -28,7 +28,7 @@ def init_ssm(key, cfg) -> dict:
     N = cfg.ssm_state
     keys = jax.random.split(key, 8)
     dt = _dtype(cfg)
-    return {
+    p = {
         # fused input projection: [z (gate), x, B, C, dt]
         "w_in": _init(keys[0], (d, 2 * din + 2 * N + H), dtype=dt),
         "conv": _init(keys[1], (cfg.conv_width, din + 2 * N), scale=0.5,
@@ -39,6 +39,9 @@ def init_ssm(key, cfg) -> dict:
         "w_out": _init(keys[2], (din, d), dtype=dt),
         "out_norm": jnp.ones((din,), dt),
     }
+    if cfg.conv_bias:
+        p["conv_bias"] = jnp.zeros((din + 2 * N,), dt)
+    return p
 
 
 def _split_proj(cfg, proj):
@@ -50,9 +53,10 @@ def _split_proj(cfg, proj):
     return z, xin, Bc, Cc, dt
 
 
-def _causal_conv(x, w, state=None):
-    """x: (B, S, D); w: (K, D) depthwise causal conv. If state (B, K-1, D)
-    is given, runs in streaming mode and returns (y, new_state)."""
+def _causal_conv(x, w, state=None, bias=None):
+    """x: (B, S, D); w: (K, D) depthwise causal conv, plus ``bias`` (D,)
+    where given. If state (B, K-1, D) is given, runs in streaming mode and
+    returns (y, new_state)."""
     K = w.shape[0]
     if state is None:
         pad = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype)
@@ -60,6 +64,8 @@ def _causal_conv(x, w, state=None):
     else:
         xp = jnp.concatenate([state.astype(x.dtype), x], axis=1)
     y = sum(xp[:, i: i + x.shape[1]] * w[i] for i in range(K))
+    if bias is not None:
+        y = y + bias
     if state is None:
         return jax.nn.silu(y)
     return jax.nn.silu(y), xp[:, -(K - 1):]
@@ -93,7 +99,8 @@ def ssd_forward(params: dict, x: jnp.ndarray, cfg,
     K = params["conv"].shape[0]
     pad = jnp.zeros((B, max(0, K - 1 - S), conv_in.shape[-1]), conv_in.dtype)
     conv_tail = jnp.concatenate([pad, conv_in[:, -(K - 1):]], axis=1)
-    conv_out = _causal_conv(conv_in, params["conv"])
+    conv_out = _causal_conv(conv_in, params["conv"],
+                            bias=params.get("conv_bias"))
     xin, Bc, Cc = jnp.split(conv_out, [xin.shape[-1], xin.shape[-1] + N],
                             axis=-1)
 
@@ -157,7 +164,8 @@ def ssd_decode_step(params: dict, x: jnp.ndarray, cfg,
     proj = x @ params["w_in"]
     z, xin, Bc, Cc, dtp = _split_proj(cfg, proj)
     conv_in = jnp.concatenate([xin, Bc, Cc], axis=-1)
-    conv_out, conv_state = _causal_conv(conv_in, params["conv"], conv_state)
+    conv_out, conv_state = _causal_conv(conv_in, params["conv"], conv_state,
+                                        params.get("conv_bias"))
     xin, Bc, Cc = jnp.split(conv_out, [xin.shape[-1], xin.shape[-1] + N],
                             axis=-1)
     dt = jax.nn.softplus(dtp.astype(jnp.float32)

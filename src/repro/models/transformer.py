@@ -1,5 +1,6 @@
 """Architecture assembly: decoder-only LMs (dense / SWA / MoE / prefix-VLM),
-encoder-decoder, Griffin hybrid, and Mamba2 SSD stacks.
+encoder-decoder, Griffin hybrid, Mamba2 SSD stacks, and SSD/attention
+hybrids (granite-4.0-h).
 
 Layer stacks are `lax.scan`-ed over stacked parameter pytrees (one layer's
 HLO regardless of depth — the only way 94-layer configs compile in
@@ -53,6 +54,10 @@ def _block_init(key, cfg, kind: str, tp_pad: int) -> Params:
         p["attn"] = L.init_attention(ks[0], cfg, tp_pad)
         p["norm2"] = jnp.ones((cfg.d_model,), dt)
         p["mlp"] = L.init_mlp(ks[1], cfg)
+    elif kind == "ssd":  # hybrid_ssd: SSD mixer, then the MLP
+        p["ssm"] = S.init_ssm(ks[0], cfg)
+        p["norm2"] = jnp.ones((cfg.d_model,), dt)
+        p["mlp"] = L.init_mlp(ks[1], cfg)
     elif kind == "ssm":
         p["ssm"] = S.init_ssm(ks[0], cfg)
         del p["norm1"]
@@ -83,6 +88,9 @@ def block_kinds(cfg) -> list[str]:
     if cfg.family == "hybrid":
         return ["local_attn" if (i + 1) % cfg.attn_every == 0 else "rec"
                 for i in range(cfg.n_layers)]
+    if cfg.family == "hybrid_ssd":
+        return ["attn" if i % cfg.attn_period == cfg.attn_offset else "ssd"
+                for i in range(cfg.n_layers)]
     return ["attn"] * cfg.n_layers
 
 
@@ -104,6 +112,13 @@ def init_model(key, cfg, tp_pad: int = 1) -> Params:
                                       "rec", n_rec, tp_pad)
         params["attn_blocks"] = _stack(jax.random.fold_in(k_blocks, 1), cfg,
                                        "local_attn", n_attn, tp_pad)
+        return params
+    if cfg.family == "hybrid_ssd":
+        n_attn = kinds.count("attn")
+        params["ssd_blocks"] = _stack(jax.random.fold_in(k_blocks, 0), cfg,
+                                      "ssd", len(kinds) - n_attn, tp_pad)
+        params["attn_blocks"] = _stack(jax.random.fold_in(k_blocks, 1), cfg,
+                                       "attn", n_attn, tp_pad)
         return params
     params["blocks"] = _stack(k_blocks, cfg, kinds[0], cfg.n_layers, tp_pad)
     return params
@@ -133,6 +148,9 @@ def _apply_attn_block(p: Params, x, cfg, positions, *, n_heads, window=0,
     if kv_override is None:
         q = L.apply_rope(q, positions, cfg.rotary_pct, cfg.rope_theta)
         k = L.apply_rope(k, positions, cfg.rotary_pct, cfg.rope_theta)
+    if cfg.attn_scale and cfg.attn_impl != "flash":
+        raise ValueError(f"attn_scale needs attn_impl 'flash', not "
+                         f"{cfg.attn_impl!r}")
     if cfg.attn_impl == "flash_pallas":
         from ..kernels.ops import pallas_flash_attention
         out = pallas_flash_attention(q, k, v, cfg.n_kv_heads, causal,
@@ -145,9 +163,15 @@ def _apply_attn_block(p: Params, x, cfg, positions, *, n_heads, window=0,
     else:
         out = blockwise_attention(q, k, v, cfg.n_kv_heads, causal=causal,
                                   window=window, prefix=prefix,
-                                  bq=cfg.flash_bq, bk=cfg.flash_bk)
-    x = x + out.reshape(B, Sq, -1) @ p["attn"]["wo"]
+                                  bq=cfg.flash_bq, bk=cfg.flash_bk,
+                                  scale=cfg.attn_scale or None)
+    x = _residual(x, out.reshape(B, Sq, -1) @ p["attn"]["wo"], cfg)
     return x, (k, v)
+
+
+def _residual(x, y, cfg):
+    """x + y, the sub-layer output ``y`` scaled by ``residual_mult``."""
+    return x + (y if cfg.residual_mult == 1.0 else y * cfg.residual_mult)
 
 
 def _apply_mlp_or_moe(p: Params, x, cfg, n_groups=1):
@@ -157,7 +181,7 @@ def _apply_mlp_or_moe(p: Params, x, cfg, n_groups=1):
         y, aux = M.moe_ffn(p["moe"], h, cfg, n_groups=n_groups)
     else:
         y = L.apply_mlp(p["mlp"], h, cfg)
-    return x + y, aux
+    return _residual(x, y, cfg), aux
 
 
 def _dense_block(p, x, cfg, positions, *, n_heads, window, prefix,
@@ -203,11 +227,11 @@ def _embed_inputs(params, cfg, batch):
         x = jnp.concatenate(
             [batch["prefix_emb"].astype(tok_emb.dtype), tok_emb], axis=1)
     else:
-        x = L.embed(params["embed"], batch["tokens"])
+        x = L.embed(params["embed"], batch["tokens"], cfg.embed_mult)
     x = L.shard_batch(x)
     B, Sx = x.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(Sx)[None], (B, Sx))
-    if cfg.rotary_pct == 0.0:
+    if cfg.rotary_pct == 0.0 and not cfg.nope:
         x = x + _sinusoidal(positions, cfg.d_model).astype(x.dtype)
     return x, positions
 
@@ -242,6 +266,11 @@ def forward_train(params: Params, cfg, batch, n_groups: int = 1):
     if cfg.family == "hybrid":
         return _hybrid_full(params, cfg, x, positions, tp_pad_heads), \
             jnp.zeros((), jnp.float32)
+
+    if cfg.family == "hybrid_ssd":
+        x, _ = hybrid_ssd_full(params, cfg, x, positions, tp_pad_heads,
+                               remat=cfg.remat, cache=False)
+        return x, jnp.zeros((), jnp.float32)
 
     if cfg.family == "ssm":
         def fn(lp, xx):
@@ -333,10 +362,89 @@ def params_n_heads(params: Params, cfg) -> int:
     """Recover the (possibly TP-padded) q-head count from the weights."""
     if cfg.family == "encdec":
         wq = params["decoder"]["attn"]["wq"]
-    elif cfg.family == "hybrid":
+    elif cfg.family in ("hybrid", "hybrid_ssd"):
         wq = params["attn_blocks"]["attn"]["wq"]
     elif cfg.family == "ssm":
         return 0
     else:
         wq = params["blocks"]["attn"]["wq"]
     return wq.shape[-1] // cfg.head_dim
+
+
+# ======================================================================
+# hybrid_ssd: SSD and attention mixers in a fixed period
+# ======================================================================
+
+def hybrid_ssd_stack(params: Params, cfg, x, ssd_fn, attn_fn, rec=None,
+                     kv=None):
+    """Runs the layer pattern as one ``lax.scan`` over its periods.  A
+    period is ``attn_period - 1`` SSD layers, unrolled, with the period's
+    attention layer applied before SSD layer ``attn_offset``.
+    ``ssd_fn(x, p, c)`` and ``attn_fn(x, p, c)`` -> ``(x, c')`` apply one
+    layer, ``c`` being that layer's slice of ``rec`` ((state, conv)) or
+    ``kv`` ((k, v)), or ``None``.  The SSD weights and ``rec`` are indexed
+    by the period's counter and ``kv`` is a scan input and output: the TPU
+    compiler copies a stacked leaf whole where it is carried through the
+    loop or a ``lax.cond``, reshaped into periods, or indexed by a second
+    loop.  Returns (x, (rec', kv')): the ``c'`` of the SSD layers stacked
+    in layer order, and of the attention layers."""
+    k1, o = cfg.attn_period - 1, cfg.attn_offset
+    n_per = cfg.n_layers // cfg.attn_period
+    if cfg.n_layers % cfg.attn_period or not 0 <= o < k1:
+        raise ValueError(f"{cfg.n_layers} layers in periods of "
+                         f"{cfg.attn_period} with attention at {o}: not a "
+                         f"whole number of periods, or attention last")
+
+    def at(tree, i):
+        return jax.tree.map(lambda a: a[i], tree)
+
+    def period(xx, inp):
+        j, ap, kc = inp
+        outs = []
+        for i in range(k1):
+            if i == o:
+                xx, kc = attn_fn(xx, ap, kc)
+            ix = j * k1 + i
+            xx, c = ssd_fn(xx, at(params["ssd_blocks"], ix), at(rec, ix))
+            outs.append(c)
+        return xx, (jax.tree.map(lambda *a: jnp.stack(a), *outs), kc)
+
+    x, (rec, kv) = jax.lax.scan(
+        period, x, (jnp.arange(n_per), params["attn_blocks"], kv))
+    return x, (jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), rec), kv)
+
+
+def hybrid_ssd_full(params: Params, cfg, x, positions, n_heads,
+                    remat: bool = False, pad_to: int | None = None,
+                    cache: bool = True):
+    """Full-sequence hybrid_ssd stack (train / prefill) -> (x, cache): the
+    SSD layers' final states and conv tails, and the attention layers' K/V
+    zero-padded to ``pad_to`` slots (defaults to the sequence length);
+    ``cache=False`` (training) keeps none."""
+    slots = max(x.shape[1], pad_to or 0)
+
+    def ssd_fn(xx, p, c):
+        h = L.rms_norm(xx, p["norm1"])
+        y, state, conv = S.ssd_forward(p["ssm"], h, cfg)
+        xx, _ = _apply_mlp_or_moe(p, _residual(xx, y, cfg), cfg)
+        return xx, ((state, conv) if cache else None)
+
+    def attn_fn(xx, p, c):
+        xx, (k, v) = _apply_attn_block(p, xx, cfg, positions,
+                                       n_heads=n_heads)
+        xx, _ = _apply_mlp_or_moe(p, xx, cfg)
+        return xx, ((_fit_seq(k, slots), _fit_seq(v, slots)) if cache
+                    else None)
+
+    if remat:
+        ssd_fn, attn_fn = jax.checkpoint(ssd_fn), jax.checkpoint(attn_fn)
+    x, (rec, kv) = hybrid_ssd_stack(params, cfg, x, ssd_fn, attn_fn)
+    if not cache:
+        return x, None
+    return x, {"state": rec[0], "conv": rec[1], "k": kv[0], "v": kv[1]}
+
+
+def _fit_seq(k, slots: int):
+    """(B, S, H, D) zero-padded to ``slots`` positions."""
+    pad = slots - k.shape[1]
+    return k if not pad else jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))

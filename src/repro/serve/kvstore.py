@@ -222,18 +222,22 @@ class KVCacheStore:
         tx = cont.tx_begin()
         try:
             for i, (path, leaf) in enumerate(leaves):
-                raw, meta = S.leaf_to_bytes(leaf)
-                writer = i % self.n_writers
-                node, proc = self.iface.place_writer(writer)
-                h = self.iface.create(f"{sdir}{path}.leaf",
-                                      oclass=self.oclass, client_node=node,
-                                      process=proc, tx=tx)
-                # async data path: leaf writes queue on the handle's
-                # submission window; the tx commit barrier drains them
-                h.write_at_async(0, raw)
-                entries[path] = {**meta, "csum": S.checksum_leaf(raw),
-                                 "file": f"{sdir}{path}.leaf",
-                                 "nbytes": int(raw.size), "writer": writer}
+                with obs.span("kv.leaf", path=path) as lsp:
+                    raw, meta = S.leaf_to_bytes(leaf)
+                    lsp["nbytes"] = int(raw.size)
+                    writer = i % self.n_writers
+                    node, proc = self.iface.place_writer(writer)
+                    h = self.iface.create(f"{sdir}{path}.leaf",
+                                          oclass=self.oclass,
+                                          client_node=node, process=proc,
+                                          tx=tx)
+                    # async data path: leaf writes queue on the handle's
+                    # submission window; the tx commit barrier drains them
+                    h.write_at_async(0, raw)
+                    entries[path] = {**meta, "csum": S.checksum_leaf(raw),
+                                     "file": f"{sdir}{path}.leaf",
+                                     "nbytes": int(raw.size),
+                                     "writer": writer}
             manifest = S.manifest_dumps(entries, {
                 "session": str(session), "step": int(step),
                 "n_writers": self.n_writers, "skeleton": _skeleton(cache),
@@ -309,23 +313,30 @@ class KVCacheStore:
         man = self._hot_manifest(session, man)
         items: dict = {}
         for path, entry in man["leaves"].items():
-            if (client_node is None and self.multipart
-                    and should_multipart(entry["nbytes"], self.mp_threshold)):
-                # hot-restore of a big leaf: fan it across the writer
-                # placement as concurrent parts instead of one stream
-                raw = multipart_read(self.iface, entry["file"],
-                                     int(entry["nbytes"]))
-            else:
-                h = self._open_leaf(entry, client_node, process)
-                raw = np.asarray(h.read_at(0, entry["nbytes"]))
-            if self.verify:
-                got = S.checksum_leaf(raw)
-                if got != entry["csum"]:
-                    raise KVStoreError(
-                        f"checksum mismatch for {session!r}{path}: "
-                        f"{got:#x} != {entry['csum']:#x}")
-            items[path] = S.bytes_to_leaf(raw, entry)
+            with obs.span("kv.leaf", path=path,
+                          nbytes=int(entry["nbytes"])):
+                items[path] = self._restore_leaf(session, path, entry,
+                                                 client_node, process)
         return S.unflatten_tree(items, _template(man["skeleton"]))
+
+    def _restore_leaf(self, session: str, path: str, entry: dict,
+                      client_node: int | None, process: int | None):
+        if (client_node is None and self.multipart
+                and should_multipart(entry["nbytes"], self.mp_threshold)):
+            # hot-restore of a big leaf: fan it across the writer
+            # placement as concurrent parts instead of one stream
+            raw = multipart_read(self.iface, entry["file"],
+                                 int(entry["nbytes"]))
+        else:
+            h = self._open_leaf(entry, client_node, process)
+            raw = np.asarray(h.read_at(0, entry["nbytes"]))
+        if self.verify:
+            got = S.checksum_leaf(raw)
+            if got != entry["csum"]:
+                raise KVStoreError(
+                    f"checksum mismatch for {session!r}{path}: "
+                    f"{got:#x} != {entry['csum']:#x}")
+        return S.bytes_to_leaf(raw, entry)
 
     # ------------- paged partial restore -------------
     def restore_slice(self, session: str, path: str, lo: int, hi: int,

@@ -15,7 +15,8 @@ from ..models import layers as L
 def make_prefill_step(cfg, pad_to: int | None = None):
     def prefill_step(params, batch):
         hidden, cache = forward_prefill(params, cfg, batch, pad_to=pad_to)
-        logits = L.lm_logits(params["embed"], hidden[:, -1:])
+        logits = L.lm_logits(params["embed"], hidden[:, -1:],
+                             cfg.logits_scaling)
         return logits, cache
     return prefill_step
 
@@ -23,7 +24,7 @@ def make_prefill_step(cfg, pad_to: int | None = None):
 def make_decode_step(cfg, greedy: bool = True):
     def decode_step(params, cache, tokens, pos):
         hidden, cache = forward_decode(params, cfg, cache, tokens, pos)
-        logits = L.lm_logits(params["embed"], hidden)
+        logits = L.lm_logits(params["embed"], hidden, cfg.logits_scaling)
         if greedy:
             next_tok = jnp.argmax(logits[:, -1], axis=-1)[:, None] \
                 .astype(jnp.int32)

@@ -17,8 +17,10 @@ CHUNK = 512
 def chunked_softmax_xent(hidden: jnp.ndarray, embed_params: dict,
                          labels: jnp.ndarray,
                          mask: jnp.ndarray | None = None,
-                         chunk: int = CHUNK) -> jnp.ndarray:
-    """hidden: (B, S, d); labels: (B, S) int32; mask: (B, S) or None.
+                         chunk: int = CHUNK,
+                         scaling: float = 1.0) -> jnp.ndarray:
+    """hidden: (B, S, d); labels: (B, S) int32; mask: (B, S) or None;
+    the logits are divided by ``scaling``.
     Returns mean masked token loss (fp32 scalar)."""
     B, S, d = hidden.shape
     if mask is None:
@@ -33,7 +35,9 @@ def chunked_softmax_xent(hidden: jnp.ndarray, embed_params: dict,
     def body(tot, inp):
         h, lab, m = inp
         h = L.rms_norm(h, embed_params["final_norm"])
-        logits = (h @ embed_params["head"]).astype(jnp.float32)
+        logits = (h @ L.head_weight(embed_params)).astype(jnp.float32)
+        if scaling != 1.0:
+            logits = logits / scaling
         lse = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, lab[..., None], axis=-1)[..., 0]
         return tot + jnp.sum((lse - gold) * m), None
@@ -53,5 +57,6 @@ def lm_loss(params: dict, cfg, hidden: jnp.ndarray, tokens: jnp.ndarray,
                              axis=1)
     mask = jnp.concatenate([jnp.ones((B, S - 1), jnp.float32),
                             jnp.zeros((B, 1), jnp.float32)], axis=1)
-    loss = chunked_softmax_xent(hidden, params["embed"], labels, mask)
+    loss = chunked_softmax_xent(hidden, params["embed"], labels, mask,
+                                scaling=cfg.logits_scaling)
     return loss + aux_weight * aux

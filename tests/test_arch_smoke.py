@@ -65,12 +65,12 @@ def test_prefill_decode_shapes(arch):
 
 
 def test_applicability_matrix():
-    """40 cells; long_500k runs only for sub-quadratic archs."""
+    """44 cells; long_500k runs only for sub-quadratic archs."""
     cells = [(a, s) for a in ARCHS for s in SHAPES]
-    assert len(cells) == 40
+    assert len(cells) == 44
     runnable = [(a, s) for a, s in cells
                 if shape_applicable(ARCHS[a], SHAPES[s])[0]]
-    assert len(runnable) == 33
+    assert len(runnable) == 36
     skipped = sorted(set(cells) - set(runnable))
     assert all(s == "long_500k" for _, s in skipped)
     subq = {a for a, s in runnable if s == "long_500k"}

@@ -125,10 +125,98 @@ def test_ssd_chunked_matches_sequential():
                                rtol=3e-3, atol=3e-3)
 
 
+def test_ssd_with_conv_bias_and_initial_state_matches_sequential():
+    """Chunked SSD with a conv bias, started from a given state, ==
+    step-by-step decode from that state (the conv starting from zeros)."""
+    cfg = dataclasses.replace(smoke_variant(ARCHS["granite-4.0-h-micro"]),
+                              ssm_chunk=8)
+    assert cfg.conv_bias
+    p = S.init_ssm(jax.random.PRNGKey(1), cfg)
+    p["conv_bias"] = jnp.asarray(rng.uniform(-0.5, 0.5,
+                                             p["conv_bias"].shape),
+                                 jnp.float32)
+    B, S_ = 2, 32
+    x = jnp.asarray(rng.normal(size=(B, S_, cfg.d_model)) * 0.3, jnp.float32)
+    state0 = jnp.asarray(rng.normal(size=(B, cfg.ssm_heads, cfg.ssm_state,
+                                          cfg.ssm_headdim)), jnp.float32)
+    y_chunked, final, tail = S.ssd_forward(p, x, cfg, initial_state=state0)
+
+    state = state0
+    conv = jnp.zeros((B, cfg.conv_width - 1,
+                      cfg.ssm_expand * cfg.d_model + 2 * cfg.ssm_state),
+                     jnp.float32)
+    ys = []
+    for t in range(S_):
+        y_t, state, conv = S.ssd_decode_step(p, x[:, t:t + 1], cfg, state,
+                                             conv)
+        ys.append(y_t)
+    y_seq = jnp.concatenate(ys, axis=1)
+    np.testing.assert_allclose(np.asarray(y_chunked), np.asarray(y_seq),
+                               rtol=3e-3, atol=3e-3)
+    np.testing.assert_allclose(np.asarray(final), np.asarray(state),
+                               rtol=3e-3, atol=3e-3)
+    np.testing.assert_allclose(np.asarray(tail), np.asarray(conv),
+                               rtol=1e-5, atol=1e-5)
+    # the bias and the initial state both reach the output
+    p0 = dict(p, conv_bias=jnp.zeros_like(p["conv_bias"]))
+    assert not np.allclose(np.asarray(S.ssd_forward(p0, x, cfg,
+                                                    initial_state=state0)[0]),
+                           np.asarray(y_chunked), atol=1e-3)
+    assert not np.allclose(np.asarray(S.ssd_forward(p, x, cfg)[0]),
+                           np.asarray(y_chunked), atol=1e-3)
+
+
+def test_hybrid_ssd_smoke_variant_keeps_both_kinds_of_layer():
+    """The smoke variant of granite keeps SSD and attention layers in a
+    whole number of periods, and the cache holds both kinds of state."""
+    from repro.models import cache_spec
+    from repro.models.transformer import block_kinds
+    full = ARCHS["granite-4.0-h-micro"]
+    kinds = block_kinds(full)
+    assert [i for i, k in enumerate(kinds) if k == "attn"] == [5, 15, 25, 35]
+    cfg = smoke_variant(full)
+    kinds = block_kinds(cfg)
+    assert cfg.family == "hybrid_ssd" and cfg.n_layers % cfg.attn_period == 0
+    assert kinds.count("attn") >= 2 and kinds.count("ssd") >= 2
+    assert cfg.n_kv_heads < cfg.n_heads          # still GQA
+    spec = cache_spec(cfg, 16, 1)
+    assert set(spec) == {"state", "conv", "k", "v"}
+    assert spec["state"].shape[0] == kinds.count("ssd")
+    assert spec["k"].shape[:3] == (kinds.count("attn"), 1, 16)
+
+
+# h2o-danube-1.8b at smoke size, PRNGKey(11) weights, 12 tokens from
+# PRNGKey(12), prefill into 16 slots, then one decode step: the first six
+# logits of each, as the code before the hybrid_ssd family computed them
+DANUBE_PREFILL = [-0.4174935221672058, 0.48753470182418823,
+                  -1.1035945415496826, 0.8552388548851013,
+                  -0.44491860270500183, -0.19133174419403076]
+DANUBE_DECODE = [-0.8570241332054138, 0.6546972990036011,
+                 -0.4108631908893585, 1.3991092443466187,
+                 -1.0463312864303589, 0.578430712223053]
+
+
+def test_danube_prefill_and_decode_logits_are_pinned():
+    from repro.serve import make_decode_step, make_prefill_step
+    cfg = smoke_variant(ARCHS["h2o-danube-1.8b"])
+    params = init_model(jax.random.PRNGKey(11), cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(12), (1, 12), 0,
+                              cfg.vocab_size)
+    lg, cache = make_prefill_step(cfg, pad_to=16)(params, {"tokens": toks})
+    tok, lg2, _ = make_decode_step(cfg)(params, cache, toks[:, -1:],
+                                        jnp.int32(12))
+    np.testing.assert_allclose(np.asarray(lg[0, -1, :6]), DANUBE_PREFILL,
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(lg2[0, -1, :6]), DANUBE_DECODE,
+                               rtol=1e-6, atol=1e-6)
+    assert int(tok[0, 0]) == 87
+
+
 @pytest.mark.parametrize("arch", ["deepseek-7b", "h2o-danube-1.8b",
                                   "paligemma-3b", "recurrentgemma-9b",
                                   "mamba2-370m", "qwen3-moe-235b-a22b",
-                                  "seamless-m4t-large-v2"])
+                                  "seamless-m4t-large-v2",
+                                  "granite-4.0-h-micro"])
 def test_prefill_then_decode_matches_full_forward(arch):
     """logits(prefill S tokens, decode token S) == logits(forward S+1)."""
     cfg = smoke_variant(ARCHS[arch])

@@ -215,7 +215,19 @@ def test_session_store_round_trip_spans():
     assert off.attrs == {"session": "s0", "nbytes": leaf_bytes}
     assert res.attrs == {"session": "s0", "nbytes": leaf_bytes}
 
-    kids, under = _tree(spans, off)
+    def through_leaves(top):
+        """``top``'s children, each ``kv.leaf`` span replaced by its own
+        children; and the ``kv.leaf`` spans."""
+        kids, under = _tree(spans, top)
+        leaves = [e for e in kids if e.name == "kv.leaf"]
+        kids = [e for e in kids if e.name != "kv.leaf"] + [
+            k for lf in leaves for k in _tree(spans, lf)[0]]
+        assert sorted(lf.attrs["path"] for lf in leaves) == sorted(
+            "/" + k for k in cache)
+        assert sum(lf.attrs["nbytes"] for lf in leaves) == leaf_bytes
+        return kids, under
+
+    kids, under = through_leaves(off)
     names = {e.name for e in kids}
     assert {"ser.to_host", "integrity.checksum", "obj.submit",
             "tx.commit"} <= names
@@ -234,7 +246,7 @@ def test_session_store_round_trip_spans():
     assert sum(c.attrs["nbytes"] for c in cell_sums) == leaf_bytes
     assert any(e.name == "obj.kv" for e in in_commit)
 
-    kids, under = _tree(spans, res)
+    kids, under = through_leaves(res)
     assert sum(e.attrs["nbytes"] for e in kids
                if e.name == "integrity.checksum") == leaf_bytes
     assert {"obj.kv", "obj.multipart"} <= {e.name for e in kids}
